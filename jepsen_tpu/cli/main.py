@@ -1772,7 +1772,15 @@ def cmd_trace(args) -> int:
     if profile_dir:
         import jax
 
-        jax.profiler.start_trace(profile_dir)
+        # the tracer's spans land in this capture too, on the device
+        # trace's clock (obs/trace.py's profiler sink).  The Python
+        # tracer stays off: its per-call events fill the Perfetto
+        # file's 1M-event cap before a run's first span
+        popts = jax.profiler.ProfileOptions()
+        popts.python_tracer_level = 0
+        jax.profiler.start_trace(
+            profile_dir, create_perfetto_trace=True, profiler_options=popts
+        )
     obs_trace.enable(capacity=args.capacity)
     try:
         rc = main(rest)
@@ -1791,9 +1799,7 @@ def cmd_trace(args) -> int:
             # triage — keep the recording, but NEVER at the artifact
             # path: `<out>.failed` cannot be mistaken for committed
             # evidence (the soak/fuzz capture discipline)
-            summary = obs_export.write_trace(
-                f"{out}.failed", merge_jax_profile_dir=profile_dir or None
-            )
+            summary = obs_export.write_trace(f"{out}.failed")
             print(
                 f"# wrapped command exited {rc}; trace kept at "
                 f"{summary['path']} (--keep-on-failure; NOT evidence)",
@@ -1807,20 +1813,14 @@ def cmd_trace(args) -> int:
                 file=sys.stderr,
             )
         return rc
-    summary = obs_export.write_trace(
-        out, merge_jax_profile_dir=profile_dir or None
-    )
-    if profile_dir and summary["jax_events"] == 0:
-        print(
-            "# note: the jax.profiler capture held no Trace-Event JSON "
-            "(XSpace-only profiler build) — the trace carries host "
-            "spans only",
-            file=sys.stderr,
-        )
+    summary = obs_export.write_trace(out)
+    if profile_dir:
+        perfetto = sorted(Path(profile_dir).rglob("perfetto_trace.json.gz"))
+        summary["perfetto"] = str(perfetto[-1]) if perfetto else None
     print(f"# trace: {json.dumps(summary)}")
     print(
         "# open it at https://ui.perfetto.dev (or chrome://tracing): "
-        f"load {summary['path']}",
+        f"load {summary.get('perfetto') or summary['path']}",
         file=sys.stderr,
     )
     return 0
@@ -2661,9 +2661,9 @@ def build_parser() -> argparse.ArgumentParser:
         "--jax-profile",
         default=None,
         metavar="DIR",
-        help="also run jax.profiler over the wrapped command and merge "
-        "any Trace-Event JSON it leaves under DIR (profiler builds that "
-        "emit only XSpace protobufs merge 0 events, reported honestly)",
+        help="also run jax.profiler over the wrapped command, writing "
+        "its capture under DIR; its perfetto_trace.json.gz holds the "
+        "program's spans and the device's ops on one clock",
     )
     tr.add_argument(
         "rest",

@@ -35,6 +35,7 @@ recorded on the packed batch and is the scatter width of the count kernels.
 from __future__ import annotations
 
 import dataclasses
+import time
 from dataclasses import dataclass
 from typing import Sequence
 
@@ -42,6 +43,8 @@ import jax
 import numpy as np
 
 from jepsen_tpu.history.ops import NO_VALUE, Op, OpF, OpType
+from jepsen_tpu.obs import metrics as obs_metrics
+from jepsen_tpu.obs import trace as obs_trace
 
 LANE = 128  # TPU lane width; default padding granule
 
@@ -121,9 +124,28 @@ def pack_row_matrices(
     a :class:`PackedHistories`.  Split out of :func:`pack_histories` so
     row explosion — the per-op half of packing — can run in parallel
     worker processes (``history.parpack``) while this assembly stays in
-    the parent."""
+    the parent.
+
+    Runs as the ``encode.pack`` span, its seconds (the conversion, and
+    with ``to_device`` the upload, included) in the global sketch
+    ``encode.pack_s{to_device=true|false}``."""
     if not mats:
         raise ValueError("cannot pack an empty batch of histories")
+    t0 = time.perf_counter()
+    with obs_trace.span("encode.pack"):
+        packed = _assemble(mats, length, value_space, to_device)
+    obs_metrics.REGISTRY.sketch(
+        "encode.pack_s", to_device="true" if to_device else "false"
+    ).add(time.perf_counter() - t0)
+    return packed
+
+
+def _assemble(
+    mats: Sequence[np.ndarray],
+    length: int | None,
+    value_space: int | None,
+    to_device: bool,
+) -> PackedHistories:
     n_max = max(m.shape[0] for m in mats)
     L = length if length is not None else _round_up(n_max, LANE)
     if n_max > L:

@@ -12,6 +12,9 @@ pipeline instead of the per-module ad-hoc timers they replace:
   every sample), with Prometheus text rendering for the service
   sidecar's ``/metrics`` endpoint.
 - :mod:`jepsen_tpu.obs.export` — Chrome-trace/Perfetto JSON emission of
-  the recorded ring, with optional merge of ``jax.profiler`` device
-  traces.
+  the recorded ring.
+
+While a ``jax.profiler`` session records, every span also lands in the
+profiler's capture on the device trace's clock (the tracer's second
+sink).
 """

@@ -10,16 +10,14 @@ Artifact discipline (the soak/fuzz capture rule): :func:`write_trace`
 writes tmp → fsync → rename, and the CLI/tool callers only invoke it on
 a COMPLETED run — a crashed run leaves no half-artifact behind.
 
-``merge_jax_profile_dir`` folds a ``jax.profiler`` capture into the same
-file when the profiler produced Trace-Event JSON (``*.trace.json[.gz]``
-under the log dir).  Newer jax versions emit only XSpace protobufs —
-then the merge honestly reports 0 merged events instead of inventing
-device rows.
+This export is for runs without a ``jax.profiler`` capture.  Under a
+capture the spans also land in the profiler's own trace, next to the
+device's ops on one clock (``obs/trace.py``; ``jepsen-tpu trace
+--jax-profile DIR`` writes its ``perfetto_trace.json.gz``).
 """
 
 from __future__ import annotations
 
-import gzip
 import json
 import os
 from pathlib import Path
@@ -79,56 +77,11 @@ def chrome_trace(records=None, t0_ns: int | None = None) -> dict:
     return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
 
 
-def merge_jax_profile(doc: dict, profile_dir: str | Path) -> int:
-    """Append any Trace-Event JSON a ``jax.profiler`` capture left under
-    ``profile_dir`` (recursive ``*.trace.json``/``*.trace.json.gz``)
-    into ``doc``, pid-shifted clear of ours.  Returns the number of
-    merged events — 0 when the capture holds only XSpace protobufs (the
-    caller should say so rather than imply device rows exist)."""
-    root = Path(profile_dir)
-    merged = 0
-    if not root.is_dir():
-        return 0
-    paths = sorted(root.rglob("*.trace.json")) + sorted(
-        root.rglob("*.trace.json.gz")
-    )
-    for p in paths:
-        try:
-            raw = (
-                gzip.decompress(p.read_bytes())
-                if p.suffix == ".gz"
-                else p.read_bytes()
-            )
-            sub = json.loads(raw)
-        except (OSError, ValueError):
-            continue
-        sub_events = (
-            sub.get("traceEvents", []) if isinstance(sub, dict) else sub
-        )
-        if not isinstance(sub_events, list):
-            continue
-        for ev in sub_events:
-            if not isinstance(ev, dict):
-                continue
-            ev = dict(ev)
-            ev["pid"] = PID + 1 + int(ev.get("pid", 0) or 0)
-            doc["traceEvents"].append(ev)
-            merged += 1
-    return merged
-
-
-def write_trace(
-    path: str | Path,
-    records=None,
-    merge_jax_profile_dir: str | Path | None = None,
-) -> dict:
+def write_trace(path: str | Path, records=None) -> dict:
     """Export the ring to ``path`` (tmp → fsync → rename).  Returns a
-    summary ``{"path", "events", "tracks", "dropped", "jax_events"}`` —
-    callers print it so the artifact's provenance is in the run log."""
+    summary ``{"path", "events", "tracks", "dropped"}`` — callers print
+    it so the artifact's provenance is in the run log."""
     doc = chrome_trace(records)
-    jax_events = 0
-    if merge_jax_profile_dir is not None:
-        jax_events = merge_jax_profile(doc, merge_jax_profile_dir)
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
     tmp = path.with_name(f"{path.name}.{os.getpid()}.tmp")
@@ -154,5 +107,4 @@ def write_trace(
         "events": len(doc["traceEvents"]),
         "tracks": n_tracks,
         "dropped": _trace.dropped(),
-        "jax_events": jax_events,
     }

@@ -3,7 +3,8 @@
 Design constraints (ISSUE 10 / OBSERVABILITY.md):
 
 - **Off is free.**  Tracing is disabled by default; a disabled
-  ``span()``/``event()`` call is one module-global read plus returning a
+  ``span()``/``event()`` call is one module-global read (plus, for
+  ``span()``, the profiler's ``is_enabled()`` probe) and returns a
   shared no-op context manager — ZERO allocations per span (pinned by
   ``tests/test_obs.py::TestDisabledOverhead``).  Hot paths therefore
   instrument unconditionally; the 2% ``obs_overhead`` bench done-bar is
@@ -29,11 +30,22 @@ Design constraints (ISSUE 10 / OBSERVABILITY.md):
 Nesting needs no explicit parent ids: Chrome-trace/Perfetto "X"
 (complete) events nest by containment of ``[ts, ts+dur]`` on one tid,
 and a ``with span(...)`` exits LIFO per thread by construction.
+
+**Second sink: the JAX profiler.**  While a ``jax.profiler`` session is
+recording, :func:`span` also opens a ``jax.profiler.TraceAnnotation`` of
+the same name, so the span lands on the capture's host plane on the
+device trace's clock.  The probe is ``TraceAnnotation.is_enabled()``,
+bound once ``jax`` has been imported by someone else (importing ``obs``
+never imports jax; a process without jax has no session to record).
+Each span reaches each active sink once: the ring (when enabled) and
+the profiler (while it records).  :func:`complete` and :func:`event`
+stay ring-only: their intervals are known only after the fact.
 """
 
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 
@@ -115,23 +127,33 @@ def _emit(st: _State, rec: tuple) -> None:
 
 
 class _Span:
-    """An enabled span: records one KIND_SPAN tuple on exit."""
+    """An enabled span: records one KIND_SPAN tuple on exit into the
+    ring (``st``, when the ring is on) and wraps the block in the
+    profiler's annotation (``ann``, while a session records)."""
 
-    __slots__ = ("_st", "name", "track", "args", "t0")
+    __slots__ = ("_st", "name", "track", "args", "t0", "_ann")
 
-    def __init__(self, st: _State, name: str, track: str | None, args):
+    def __init__(self, st: _State | None, name: str, track: str | None,
+                 args, ann=None):
         self._st = st
         self.name = name
         self.track = track
         self.args = args
         self.t0 = 0
+        self._ann = ann
 
     def __enter__(self) -> "_Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._st is None:
+            return
         _emit(
             self._st,
             (
@@ -159,14 +181,34 @@ class _Noop:
 
 _NOOP = _Noop()
 
+#: ``jax.profiler.TraceAnnotation`` once jax is imported (None before)
+_annotation = None
+
+
+def _bind_profiler():
+    """The profiler's annotation class, or None while no jax is loaded
+    in this process (then no session can be recording)."""
+    global _annotation
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+
+    _annotation = TraceAnnotation
+    return TraceAnnotation
+
 
 def span(name: str, track: str | None = None, args: dict | None = None):
     """``with span("pipeline.produce", track="lane0"): ...`` — records a
-    complete span over the block.  Disabled: returns the shared no-op
-    (no allocation).  ``args`` must be a pre-built dict or None — build
-    it behind :func:`is_enabled` on hot paths so the off-path never
-    allocates."""
+    complete span over the block into the ring, and into a recording
+    ``jax.profiler`` session.  Both off: returns the shared no-op (no
+    allocation; the cost is one ``is_enabled()`` probe).  ``args`` must
+    be a pre-built dict or None — build it behind :func:`is_enabled` on
+    hot paths so the off-path never allocates; the profiler's copy of
+    the span carries the name only."""
     st = _state
+    ann = _annotation or _bind_profiler()
+    if ann is not None and ann.is_enabled():
+        return _Span(st, name, track, args, ann(name))
     if st is None:
         return _NOOP
     return _Span(st, name, track, args)

@@ -56,6 +56,7 @@ degenerate-history host-fallback splices) and both crash contracts;
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -193,12 +194,15 @@ class PipelineStats:
     )
 
     def add_busy(
-        self, stage: str, t0: float, t1: float, track: str | None = None
+        self, stage: str, t0: float, t1: float, track: str | None = None,
+        traced: bool = False,
     ) -> None:
         """THE stage accounting point (``t0``/``t1`` from
         ``time.perf_counter()``): run-scoped counter + global cumulative
         counter + per-batch check-latency sketch + trace span, in one
-        call, so no executor keeps private busy-second arithmetic."""
+        call, so no executor keeps private busy-second arithmetic.
+        ``traced``: a live span already covered the interval
+        (:meth:`busy`), so the ring gets no second record of it."""
         dt = t1 - t0
         self.metrics.counter("pipeline.stage_busy_s", stage=stage).inc(dt)
         obs_metrics.REGISTRY.counter(
@@ -209,15 +213,24 @@ class PipelineStats:
             # source for obs_overhead and the stats snapshot
             self.metrics.sketch("pipeline.check_batch_s").add(dt)
             obs_metrics.REGISTRY.sketch("pipeline.check_batch_s").add(dt)
-        obs_trace.complete(f"pipeline.{stage}", t0, t1, track=track)
+        if not traced:
+            obs_trace.complete(f"pipeline.{stage}", t0, t1, track=track)
+
+    @contextlib.contextmanager
+    def busy(self, stage: str, track: str | None = None):
+        """Account the block as ``stage`` busy time under a live
+        ``pipeline.<stage>`` span (busy time counted on success; a
+        crashing stage aborts or quarantines anyway)."""
+        with obs_trace.span(f"pipeline.{stage}", track=track):
+            t0 = time.perf_counter()
+            yield
+            t1 = time.perf_counter()
+        self.add_busy(stage, t0, t1, track=track, traced=True)
 
     def run_stage(self, stage: str, fn, arg, track: str | None = None):
-        """Run ``fn(arg)`` as an accounted stage (busy time counted on
-        success; a crashing stage aborts the run anyway)."""
-        t0 = time.perf_counter()
-        out = fn(arg)
-        self.add_busy(stage, t0, time.perf_counter(), track=track)
-        return out
+        """Run ``fn(arg)`` as an accounted stage (:meth:`busy`)."""
+        with self.busy(stage, track=track):
+            return fn(arg)
 
     def note_retry(
         self, stage: str, index: int, exc: BaseException,
@@ -255,6 +268,8 @@ class PipelineStats:
         return self.metrics.sketch("pipeline.check_batch_s").quantile(q)
 
     def finalize(self) -> "PipelineStats":
+        # the executor's three stages; ``assemble`` (verdict assembly
+        # after the executor returns) stays out of both fractions
         busy = self.produce_busy_s + self.place_busy_s + self.check_busy_s
         self.stage_overlap_frac = (
             max(0.0, busy - self.wall_s) / busy if busy > 0 else 0.0
@@ -286,6 +301,18 @@ class _Poison:
         self.index = index
         self.stage = stage
         self.errors = list(errors)
+
+
+def _take_produced(q: queue.Queue):
+    """The consumer's blocking take from the producer's queue: the
+    ``pipeline.wait`` span and the global ``pipeline.wait_s{on=produce}``
+    seconds (the place/check thread idle on ingest)."""
+    with obs_trace.span("pipeline.wait"):
+        t0 = time.perf_counter()
+        got = q.get()
+        dt = time.perf_counter() - t0
+    obs_metrics.REGISTRY.counter("pipeline.wait_s", on="produce").inc(dt)
+    return got
 
 
 def _default_collect(raw):
@@ -393,7 +420,8 @@ def _run_pipeline_failfast(
     def drain_one() -> None:
         nonlocal last_ready
         i, raw, t_disp = in_flight.pop(0)
-        results[i] = collect(raw)
+        with obs_trace.span("pipeline.collect"):
+            results[i] = collect(raw)
         t_ready = time.perf_counter()
         # device occupancy: the interval this batch actually had the
         # device, serialized against the previous batch's completion
@@ -402,7 +430,7 @@ def _run_pipeline_failfast(
 
     try:
         while True:
-            got = q.get()
+            got = _take_produced(q)
             if got is _STOP:
                 break
             if isinstance(got, _Crash):
@@ -494,7 +522,8 @@ def _run_pipeline_elastic(
             try:
                 # a dispatch error surfaces here (async programs raise
                 # at block time)
-                got = collect(raw)
+                with obs_trace.span("pipeline.collect"):
+                    got = collect(raw)
                 break
             except Exception as e:
                 errors.append(e)
@@ -528,7 +557,7 @@ def _run_pipeline_elastic(
 
     try:
         while True:
-            got = q.get()
+            got = _take_produced(q)
             if got is _STOP:
                 break
             if isinstance(got, _Crash):
@@ -629,7 +658,8 @@ def _run_lanes_failfast(
         def drain_one():
             nonlocal last_ready
             k, raw, t_disp = in_flight.pop(0)
-            results[k] = collect(raw)
+            with obs_trace.span("pipeline.collect", track=track):
+                results[k] = collect(raw)
             t_ready = time.perf_counter()
             stats.add_busy(
                 "check", max(t_disp, last_ready), t_ready, track=track
@@ -735,7 +765,8 @@ def _run_lanes_elastic(units, fams, depth, stats, results) -> None:
             k, raw, t_disp, attempts = in_flight.pop(0)
             current = (k, attempts)
             try:
-                got = collect(raw)
+                with obs_trace.span("pipeline.collect", track=track):
+                    got = collect(raw)
             except Exception as e:
                 fail(k, "collect", attempts, e)
                 current = None
@@ -2374,15 +2405,19 @@ def _check_sources_lanes(
         collected = _resolve_quarantines(fams[0], units, collected, stats)
     stats.dropped = len(dropped)
     if reduce:
-        merged = _merge_reduced(fams[0], units, collected)
+        with stats.busy("assemble"):
+            merged = _merge_reduced(fams[0], units, collected)
         merged["dropped"] = len(dropped)
         stats.histories = merged["histories"]
         return merged, stats
     out: list = [None] * len(sources)
-    for k, (unit, col) in enumerate(zip(units, collected)):
-        conv = _convert_unit(fams[0], workload, unit, col, stats, fail_fast)
-        for j, r in enumerate(conv):
-            out[ordered_idx[unit_indices[k][j]]] = r
+    with stats.busy("assemble"):
+        for k, (unit, col) in enumerate(zip(units, collected)):
+            conv = _convert_unit(
+                fams[0], workload, unit, col, stats, fail_fast
+            )
+            for j, r in enumerate(conv):
+                out[ordered_idx[unit_indices[k][j]]] = r
     for i, reason in dropped.items():
         out[i] = _dropped_result(workload, reason)
     stats.histories = len(out)
@@ -2431,9 +2466,12 @@ def check_sources(
     history's verdict survives.  ``fail_fast=True`` restores the
     abort-all :class:`PipelineError` contract; the ``serial=True``
     triage path always fails fast (it exists to surface the first
-    error loudly)."""
+    error loudly).
+
+    Every call adds its histories to the global ``pipeline.histories``
+    counter: the denominator of the per-history stage seconds there."""
     if lanes is not None and not serial:
-        return _check_sources_lanes(
+        out = _check_sources_lanes(
             workload,
             list(sources),
             chunk=chunk,
@@ -2443,6 +2481,28 @@ def check_sources(
             fail_fast=fail_fast,
             **opts,
         )
+    else:
+        out = _check_sources_chunked(
+            workload, sources, chunk=chunk, serial=serial, depth=depth,
+            reduce=reduce, fail_fast=fail_fast, **opts,
+        )
+    obs_metrics.REGISTRY.counter("pipeline.histories").inc(out[1].histories)
+    return out
+
+
+def _check_sources_chunked(
+    workload: str,
+    sources: Sequence[Any],
+    *,
+    chunk: int,
+    serial: bool,
+    depth: int,
+    reduce: bool,
+    fail_fast: bool,
+    **opts,
+):
+    """One producer, one consumer over contiguous chunks (or the serial
+    triage path): :func:`check_sources` without ``lanes``."""
     opts = dict(opts)
     opts["reduce"] = reduce
     opts.setdefault("chunk_pad", _pad_for(chunk, opts))
@@ -2485,15 +2545,19 @@ def check_sources(
         if not fail_fast:
             collected = _resolve_quarantines(fam, items, collected, stats)
     if reduce:
-        merged = _merge_reduced(fam, items, collected)
+        with stats.busy("assemble"):
+            merged = _merge_reduced(fam, items, collected)
         merged["dropped"] = 0
         stats.histories = merged["histories"]
         return merged, stats
     results: list[dict] = []
-    for it, col in zip(items, collected):
-        results.extend(
-            _convert_unit(fam, workload, it, col, stats, fail_fast or serial)
-        )
+    with stats.busy("assemble"):
+        for it, col in zip(items, collected):
+            results.extend(
+                _convert_unit(
+                    fam, workload, it, col, stats, fail_fast or serial
+                )
+            )
     stats.histories = len(results)
     return results, stats
 

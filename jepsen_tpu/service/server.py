@@ -25,14 +25,19 @@ Ops:
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
+import socket
 import socketserver
 import threading
+import time
 from typing import Any
 
 import numpy as np
 
+from jepsen_tpu.obs import metrics as obs_metrics
+from jepsen_tpu.obs import trace as obs_trace
 from jepsen_tpu.service.protocol import (
     ProtocolError,
     TornPayloadError,
@@ -62,6 +67,24 @@ SUB_DROP_ENV = "JEPSEN_TPU_SERVE_SUB_DROP_AFTER"
 SUBSCRIBE_IDLE_TIMEOUT_S = 120.0
 
 
+def _check_stage_s(stage: str):
+    """The global sketch ``service.stage_s{op=check,stage=...}``."""
+    return obs_metrics.REGISTRY.sketch(
+        "service.stage_s", op="check", stage=stage
+    )
+
+
+@contextlib.contextmanager
+def _check_stage(stage: str):
+    """One host stage of the ``check`` op: the ``service.<stage>`` span,
+    its seconds into :func:`_check_stage_s` (on success)."""
+    with obs_trace.span(f"service.{stage}"):
+        t0 = time.perf_counter()
+        yield
+        dt = time.perf_counter() - t0
+    _check_stage_s(stage).add(dt)
+
+
 def _pad_batch_axis(tree, multiple: int):
     """Zero/False-pad every leaf's axis 0 to a multiple (padded histories
     are fully masked → ignored); returns ``(padded, original_B)``."""
@@ -82,6 +105,10 @@ def _pad_batch_axis(tree, multiple: int):
 def _check_arrays(
     arrays: dict[str, np.ndarray], value_space: int, mesh=None
 ) -> dict[str, Any]:
+    """The ``check`` op under the device lock, in three host stages:
+    ``place`` (uploads), ``kernel`` (dispatch until the verdict tensors
+    are ready), ``render`` (device-to-host reads, the result maps)."""
+    import jax
     import jax.numpy as jnp
 
     from jepsen_tpu.checkers.queue_lin import queue_lin_tensors_to_results
@@ -90,60 +117,64 @@ def _check_arrays(
     missing = [k for k in REQUIRED_ARRAYS if k not in arrays]
     if missing:
         raise ProtocolError(f"missing arrays: {missing}")
-    f = jnp.asarray(arrays["f"], jnp.int32)
-    type_ = jnp.asarray(arrays["type"], jnp.int32)
-    value = jnp.asarray(arrays["value"], jnp.int32)
-    mask = jnp.asarray(arrays["mask"].astype(bool))
-
-    if mesh is not None:
-        # mesh-wide check: the same sharded programs the driver dryruns
-        import jax
-        from jax.sharding import NamedSharding
-
-        from jepsen_tpu.parallel.mesh import (
-            HIST_AXIS,
-            SEQ_AXIS,
-            _queue_lin_program,
-            _row_spec,
-            _total_queue_program,
-        )
-
-        (f, type_, value, mask), B = _pad_batch_axis(
-            (f, type_, value, mask), mesh.shape[HIST_AXIS]
-        )
-        # the op axis must divide the seq shards too: pad with masked rows
-        # (appended at the end, so real row positions are unchanged)
-        pad_l = (-f.shape[1]) % mesh.shape[SEQ_AXIS]
-        if pad_l:
-            widths = ((0, 0), (0, pad_l))
-            f = jnp.pad(f, widths)
-            type_ = jnp.pad(type_, widths)
-            value = jnp.pad(value, widths)
-            mask = jnp.pad(mask, widths)
-        # place once; both programs then consume the committed arrays
-        sh = NamedSharding(mesh, _row_spec())
-        f, type_, value, mask = (
-            jax.device_put(x, sh) for x in (f, type_, value, mask)
-        )
-        tq = _total_queue_program(mesh, value_space)(f, type_, value, mask)
-        ql = _queue_lin_program(mesh, value_space)(f, type_, value, mask)
-    else:
-        from jepsen_tpu.checkers.fused import _combined_batch
-
-        # the canonical single-program combined check (checkers/fused.py)
-        tq, ql = _combined_batch(f, type_, value, mask, value_space)
+    with _check_stage("place"):
+        f = jnp.asarray(arrays["f"], jnp.int32)
+        type_ = jnp.asarray(arrays["type"], jnp.int32)
+        value = jnp.asarray(arrays["value"], jnp.int32)
+        mask = jnp.asarray(arrays["mask"].astype(bool))
         B = f.shape[0]
-    tq_results = _tensors_to_results(tq)[:B]
-    ql_results = queue_lin_tensors_to_results(ql)[:B]
-    out = []
-    for q, l in zip(tq_results, ql_results):
-        out.append(
-            {
-                "queue": _jsonable(q),
-                "linear": _jsonable(l),
-                "valid?": bool(q["valid?"] and l["valid?"]),
-            }
-        )
+        if mesh is not None:
+            # mesh-wide check: the sharded programs of parallel/mesh.py
+            from jax.sharding import NamedSharding
+
+            from jepsen_tpu.parallel.mesh import HIST_AXIS, SEQ_AXIS, _row_spec
+
+            (f, type_, value, mask), B = _pad_batch_axis(
+                (f, type_, value, mask), mesh.shape[HIST_AXIS]
+            )
+            # the op axis must divide the seq shards too: pad with masked
+            # rows (appended at the end, so real row positions are unchanged)
+            pad_l = (-f.shape[1]) % mesh.shape[SEQ_AXIS]
+            if pad_l:
+                widths = ((0, 0), (0, pad_l))
+                f = jnp.pad(f, widths)
+                type_ = jnp.pad(type_, widths)
+                value = jnp.pad(value, widths)
+                mask = jnp.pad(mask, widths)
+            # place once; both programs then consume the committed arrays
+            sh = NamedSharding(mesh, _row_spec())
+            f, type_, value, mask = (
+                jax.device_put(x, sh) for x in (f, type_, value, mask)
+            )
+
+    with _check_stage("kernel"):
+        if mesh is not None:
+            from jepsen_tpu.parallel.mesh import (
+                _queue_lin_program,
+                _total_queue_program,
+            )
+
+            tq = _total_queue_program(mesh, value_space)(f, type_, value, mask)
+            ql = _queue_lin_program(mesh, value_space)(f, type_, value, mask)
+        else:
+            from jepsen_tpu.checkers.fused import _combined_batch
+
+            # the canonical single-program combined check (checkers/fused.py)
+            tq, ql = _combined_batch(f, type_, value, mask, value_space)
+        jax.block_until_ready((tq, ql))
+
+    with _check_stage("render"):
+        tq_results = _tensors_to_results(tq)[:B]
+        ql_results = queue_lin_tensors_to_results(ql)[:B]
+        out = []
+        for q, l in zip(tq_results, ql_results):
+            out.append(
+                {
+                    "queue": _jsonable(q),
+                    "linear": _jsonable(l),
+                    "valid?": bool(q["valid?"] and l["valid?"]),
+                }
+            )
     return {"op": "result", "results": out}
 
 
@@ -236,7 +267,14 @@ class _Handler(socketserver.BaseRequestHandler):
         server: CheckerServer = self.server  # type: ignore[assignment]
         while True:
             try:
-                header, arrays = recv_frame(self.request)
+                # wait for the client's next request outside the frame
+                # stage: it times decoding once the first bytes are here
+                if not self.request.recv(1, socket.MSG_PEEK):
+                    return
+                t0 = time.perf_counter()
+                with obs_trace.span("service.frame"):
+                    header, arrays = recv_frame(self.request)
+                decode_s = time.perf_counter() - t0
             except TornPayloadError as e:
                 # the frame was fully consumed (connection still in
                 # sync): quarantine exactly the poisoned stream, reply,
@@ -260,7 +298,13 @@ class _Handler(socketserver.BaseRequestHandler):
                     return
             try:
                 reply = server.dispatch(header, arrays)
-                send_frame(self.request, reply)
+                t0 = time.perf_counter()
+                with obs_trace.span("service.frame"):
+                    send_frame(self.request, reply)
+                if header.get("op") == "check":
+                    _check_stage_s("frame").add(
+                        decode_s + time.perf_counter() - t0
+                    )
             except ProtocolError as e:
                 send_frame(self.request, {"op": "error", "error": str(e)})
             except Exception as e:  # noqa: BLE001 — report, keep serving
@@ -314,13 +358,9 @@ class _Handler(socketserver.BaseRequestHandler):
                 try:
                     w = q.get(timeout=0.5)
                 except queue_mod.Empty:
-                    import time as _time
-
                     if deadline is None:
-                        deadline = (
-                            _time.monotonic() + SUBSCRIBE_IDLE_TIMEOUT_S
-                        )
-                    elif _time.monotonic() > deadline:
+                        deadline = time.monotonic() + SUBSCRIBE_IDLE_TIMEOUT_S
+                    elif time.monotonic() > deadline:
                         send_frame(self.request, {
                             "op": "subscribe-timeout", "stream": sid,
                             "idle_s": SUBSCRIBE_IDLE_TIMEOUT_S,
@@ -532,29 +572,25 @@ class CheckerServer(socketserver.ThreadingTCPServer):
     def dispatch(
         self, header: dict[str, Any], arrays: dict[str, np.ndarray]
     ) -> dict[str, Any]:
-        import time as _time
-
-        from jepsen_tpu.obs import trace as obs_trace
-
         op = header.get("op")
         if op in ("check", "check-stream", "check-elle"):
-            t0 = _time.perf_counter()
-            try:
-                reply = self._dispatch(op, header, arrays)
-            except Exception:
-                self.metrics.counter("service.errors", op=op).inc()
-                raise
-            dt = _time.perf_counter() - t0
+            # per-thread track (the handler thread's name), NOT one
+            # shared "service" track: concurrent requests overlap in
+            # time (t0 is taken before the device lock), and overlapping
+            # spans on one tid would render as bogus nesting
+            with obs_trace.span(f"service.{op}"):
+                t0 = time.perf_counter()
+                try:
+                    reply = self._dispatch(op, header, arrays)
+                except Exception:
+                    self.metrics.counter("service.errors", op=op).inc()
+                    raise
+                dt = time.perf_counter() - t0
             self.metrics.counter("service.requests", op=op).inc()
             self.metrics.counter("service.histories", op=op).inc(
                 len(reply.get("results", ()))
             )
             self.metrics.sketch("service.check_latency_s", op=op).add(dt)
-            # per-thread track (the handler thread's name), NOT one
-            # shared "service" track: concurrent requests overlap in
-            # time (t0 is taken before the device lock), and overlapping
-            # spans on one tid would render as bogus nesting
-            obs_trace.complete(f"service.{op}", t0, t0 + dt)
             return reply
         if op in _STREAM_OPS:
             self.metrics.counter("service.requests", op=op).inc()
@@ -577,8 +613,12 @@ class CheckerServer(socketserver.ThreadingTCPServer):
             value_space = int(header.get("value_space", 0))
             if value_space <= 0:
                 raise ProtocolError("value_space must be positive")
-            with self._device_lock:
+            with _check_stage("lock"):
+                self._device_lock.acquire()
+            try:
                 return _check_arrays(arrays, value_space, mesh=self._mesh)
+            finally:
+                self._device_lock.release()
         if op == "check-stream":
             space = int(header.get("space", 0))
             if space <= 0:

@@ -88,6 +88,33 @@ def test_queue_combined_verdict_L1024_V1024(one_chip):
     _compile(_combined_batch, *cols, value_space=1024)
 
 
+def test_queue_flat_verdict_B64_L1024_V512(one_chip):
+    """The single-device pipeline's queue program as the replay runs it:
+    the combined verdict flattened into one int32 buffer inside one
+    donating jit of a lambda, whose module name (``jit__lambda``) the
+    benchmark's roofline reader matches; 64 histories, L=1024, V=512."""
+    import jax
+
+    from jepsen_tpu.checkers.fused import combined_tensor_check
+    from jepsen_tpu.history.encode import pack_histories
+    from jepsen_tpu.history.synth import SynthSpec, synth_batch
+    from jepsen_tpu.parallel.pipeline import flatten_verdict
+
+    packed = pack_histories(
+        [sh.ops for sh in synth_batch(2, SynthSpec(n_ops=470))],
+        length=1024, value_space=512, to_device=False,
+    )
+    batch = jax.tree.map(lambda c: _sds(one_chip, c, 64), packed)
+    program = jax.jit(
+        lambda p: flatten_verdict(combined_tensor_check(p, packed_out=True)),
+        donate_argnums=0,
+    )
+    compiled = _compile(program, batch)
+    assert compiled.as_text().startswith("HloModule jit__lambda,")
+    # 10 count and flag columns, 8 classes of 512 / 32 bitplanes
+    assert "s32[64,138]" in compiled.as_text()
+
+
 def test_pallas_queue_stats_compiles_through_mosaic(one_chip):
     """The Pallas kernel with ``interpret=False`` lowers to a Mosaic
     custom call, not to the interpreter's XLA."""

@@ -25,11 +25,9 @@ from types import SimpleNamespace
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
+import gen  # noqa: E402
 import reference  # noqa: E402
 import run  # noqa: E402
-
-#: the checker options the program's replay entry takes, per family
-PROGRAM_OPTS = {"queue": {"delivery"}, "stream": {"append_fail"}}
 
 
 def _reference_check(cell, opts):
@@ -54,7 +52,7 @@ def control_hook(cell):
     """``hook(driver)`` putting the configuration's control in place."""
     opts = dict(cell.config["checker"], **cell.config["control"])
     fam = cell.config["family"]
-    own = set(cell.config["control"]) <= PROGRAM_OPTS[fam]
+    own = set(cell.config["control"]) <= gen.family(fam).PROGRAM_OPTS
 
     def hook(driver):
         if cell.traffic["driver"] == "replay":

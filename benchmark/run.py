@@ -6,7 +6,9 @@ Everything a cell is made of is found by name (``BENCHMARK.json`` at the
 checkout's root):
 
 - the cell's ``config`` -> ``benchmark/configs/<config>.json`` (family,
-  history shapes, campaign, the guarantees the checker is held to);
+  history shapes, campaign, the guarantees the checker is held to), and
+  its ``family`` -> ``benchmark/families/<family>.py`` (the generator and
+  the plain reference, ``benchmark/gen.py``);
 - its ``traffic`` -> ``benchmark/traffic/<mix>.json`` (parameters, among
   them the ``driver``: ``benchmark/drivers/<driver>.py``, the entry the
   window drives);
@@ -132,7 +134,7 @@ def synthesize(cell: Cell, workdir: Path, procs):
         (conf["family"], conf["spec"], hseed, inject,
          str(workdir / f"{k:05d}.jsonl"))
         for k, (hseed, inject) in enumerate(
-            gen.plan(conf["campaign"], cell.seed, count))
+            gen.plan(conf["family"], conf["campaign"], cell.seed, count))
     ]
     return procs.starmap_async(gen.make, jobs,
                                chunksize=max(1, len(jobs) // 64))
@@ -184,7 +186,9 @@ def compare(cell: Cell, campaign: list[dict], answers, procs) -> dict:
     idx = sorted({i for i, _ in got})
     jobs = [(fam, opts, campaign[i]["path"], False) for i in idx]
     ref = dict(zip(idx, procs.map(reference.run, jobs, chunksize=1)))
-    mismatched = sum(1 for i, g in got if not reference.same(g, ref[i]))
+    labels = gen.family(fam).LABELS
+    mismatched = sum(1 for i, g in got
+                     if not reference.same(g, ref[i], labels))
     return {"mismatched": mismatched, "compared": len(got)}
 
 

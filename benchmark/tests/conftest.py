@@ -1,6 +1,20 @@
 """The benchmark's tests run on the CPU, with four virtual devices for the
 mesh cell; ``tiny`` makes a checkout whose configurations are cut to a
-size a test run holds."""
+size a test run holds.
+
+Everything a test needs of one configuration, traffic mix or cell is a
+file of its own, found by name:
+
+- ``tiny/configs/<config>.json``: the configuration's cut, merged into
+  each of its top-level groups (every configuration has one);
+- ``tiny/traffic/<mix>.json``: a traffic mix's cut, where it needs one;
+- ``faults/<cell>.json``: where the cell's faults are planted
+  (``test_correct.py``);
+- ``parity/<family>.json``: the program's synth that the family's
+  generator copies (``test_gen.py``);
+- ``work/<metric>.json``: a roofline reader's work cases
+  (``test_peaks.py``).
+"""
 
 import json
 import os
@@ -17,32 +31,38 @@ if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+HOOKS = Path(__file__).resolve().parent
 sys.path[:0] = [str(BENCH), str(ROOT)]
 
-#: per configuration: the sizes a test run holds
-TINY = {
-    "qq-replay": {"spec": {"n_ops": 60}, "campaign": {"histories": 24}},
-    "stream-10k": {"spec": {"n_ops": 300}, "campaign": {"histories": 6}},
-}
+BENCHMARK = json.loads((ROOT / "BENCHMARK.json").read_text())
+CELLS = [w["name"] for w in BENCHMARK["workloads"]]
+
+
+def _cut(path: Path, cut: dict) -> None:
+    c = json.loads(path.read_text())
+    for k, v in cut.items():
+        c[k] = {**c[k], **v} if isinstance(v, dict) else v
+    path.write_text(json.dumps(c))
 
 
 def make_tiny(dest: Path) -> Path:
     """A checkout at ``dest``: ``BENCHMARK.json`` and ``benchmark/`` copied
-    with each configuration cut to ``TINY``; the program linked in."""
+    with each configuration and traffic mix cut to its ``tiny`` file; the
+    program linked in."""
     shutil.copy(ROOT / "BENCHMARK.json", dest / "BENCHMARK.json")
     shutil.copytree(BENCH, dest / "benchmark",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     for name in ("jepsen_tpu", "native"):
         (dest / name).symlink_to(ROOT / name)
-    for conf, cut in TINY.items():
-        p = dest / "benchmark" / "configs" / f"{conf}.json"
-        c = json.loads(p.read_text())
-        for k, v in cut.items():
-            c[k] = {**c[k], **v}
-        p.write_text(json.dumps(c))
-    side = dest / "benchmark" / "traffic" / "sidecar-closed.json"
-    side.write_text(json.dumps({**json.loads(side.read_text()),
-                                "histories": 8}))
+    for conf in BENCHMARK["configs"]:
+        cut = HOOKS / "tiny" / "configs" / f"{conf['name']}.json"
+        if not cut.is_file():
+            raise FileNotFoundError(f"configuration {conf['name']!r} has no "
+                                    f"test cut {cut}")
+        _cut(dest / conf["file"], json.loads(cut.read_text()))
+    for cut in sorted((HOOKS / "tiny" / "traffic").glob("*.json")):
+        _cut(dest / "benchmark" / "traffic" / cut.name,
+             json.loads(cut.read_text()))
     return dest
 
 

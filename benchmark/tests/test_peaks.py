@@ -1,13 +1,20 @@
-"""The peaks table and the roofline arithmetic of the kernel readers."""
+"""The peaks table and the roofline arithmetic of the kernel readers.
+
+Each roofline reader's ``work(shapes)`` is held to its file
+``work/<metric>.json``: shapes of histories, the ``(bytes, ops)`` they
+take, and why."""
 
 import importlib.util
-from pathlib import Path
+import json
 
 import pytest
 
 import peaks
+from conftest import BENCH, BENCHMARK, HOOKS
 
-METRICS = Path(__file__).resolve().parents[1] / "metrics"
+METRICS = BENCH / "metrics"
+WORK = {p.stem: json.loads(p.read_text())
+        for p in sorted((HOOKS / "work").glob("*.json"))}
 
 
 def _reader(name):
@@ -31,17 +38,14 @@ def test_roofline_takes_the_larger_bound():
     assert bound == "ops" and share == pytest.approx(50.0)
 
 
-def test_queue_work_from_shapes():
-    m = _reader("queue_check_roofline")
-    # 1000 rows of values < 256: 1 + 1 bytes a row; 8 classes of 200 bits
-    assert m.work([{"rows": 1000, "space": 200}]) == (2000 + 200, 8000)
-    # values past 256 take two bytes
-    assert m.work([{"rows": 10, "space": 300}]) == (30 + 300, 80)
+def test_every_roofline_has_its_work_cases():
+    for m in BENCHMARK["per_layer"]:
+        if m["name"].endswith("_roofline"):
+            assert m["name"] in WORK
 
 
-def test_stream_work_from_shapes():
-    m = _reader("stream_check_roofline")
-    # 54,000 rows over 10,000 values and offsets: 1 + 2 bytes a row;
-    # 6 verdict sets of 10,000 bits
-    assert m.work([{"rows": 54_000, "space": 10_000}]) == (
-        54_000 * 3 + 7_500, 540_000)
+@pytest.mark.parametrize("name,case", [
+    (n, c) for n, w in WORK.items() for c in w["cases"]])
+def test_work_from_shapes(name, case):
+    assert _reader(name).work(case["shapes"]) == tuple(case["work"]), \
+        case["why"]

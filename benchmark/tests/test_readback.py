@@ -1,15 +1,16 @@
 """The verdict read-back's per-layer metric: a traced CPU rehearsal of
-each replay cell reports ``collect_ms_per_hist.replay``, and the metric
-names those cells."""
-
-import json
+each cell it names reports ``collect_ms_per_hist.replay``, and it names
+the replay cells."""
 
 import pytest
 
-from conftest import BENCH, run_tiny
+from conftest import BENCH, BENCHMARK, run_tiny
+
+import run
 
 NAME = "collect_ms_per_hist.replay"
-CELLS = ["qq-replay.fresh", "stream-10k.fresh", "qq-replay.mesh4"]
+(METRIC,) = [m for m in BENCHMARK["per_layer"] if m["name"] == NAME]
+CELLS = METRIC["workloads"]
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -21,7 +22,7 @@ def test_traced_rehearsal_reports_collect(tiny, cell):
 
 
 def test_collect_metric_names_the_replay_cells():
-    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    (m,) = [m for m in bench["per_layer"] if m["name"] == NAME]
-    assert m["workloads"] == CELLS and m["moves"] == "histories_per_s"
+    replay = [w["name"] for w in BENCHMARK["workloads"]
+              if run.find_cell(w["name"]).traffic["driver"] == "replay"]
+    assert CELLS == replay and METRIC["moves"] == "histories_per_s"
     assert (BENCH / "metrics" / f"{NAME}.py").is_file()

@@ -3,21 +3,18 @@ traced CPU rehearsal reports each of them in the cells it names, and the
 run leaves every file of the benchmark as it was."""
 
 import hashlib
-import json
 
 import pytest
 
-from conftest import BENCH, run_tiny
+from conftest import BENCH, BENCHMARK, CELLS, run_tiny
 
+import run
+
+#: per cell, the metrics its traced runs read from the program's spans
 SPAN_METRICS = {
-    "qq-replay.fresh": {"assemble_ms_per_hist.replay",
-                        "produce_wait_ms_per_hist.replay"},
-    "qq-replay.sidecar": {"server_place_p50_ms.sidecar",
-                          "server_kernel_p50_ms.sidecar",
-                          "server_render_p50_ms.sidecar",
-                          "server_frame_p50_ms.sidecar",
-                          "client_pack_p50_ms.sidecar"},
-}
+    cell: {m["name"] for m in run.find_cell(cell).per_layer
+           if m["source"] == "program_span"}
+    for cell in CELLS}
 
 
 def _digests(root):
@@ -38,9 +35,7 @@ def test_traced_rehearsal_reports_the_span_metrics(tiny, cell):
 
 
 def test_each_span_metric_names_its_cells():
-    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    cells = {m["name"]: set(m["workloads"]) for m in bench["per_layer"]}
-    for cell, names in SPAN_METRICS.items():
-        for name in names:
-            assert cell in cells[name]
-            assert (BENCH / "metrics" / f"{name}.py").is_file()
+    for m in BENCHMARK["per_layer"]:
+        if m["source"] == "program_span":
+            assert set(m.get("workloads", CELLS)) <= set(CELLS)
+            assert (BENCH / "metrics" / f"{m['name']}.py").is_file()

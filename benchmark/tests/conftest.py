@@ -8,8 +8,9 @@ file of its own, found by name:
 - ``tiny/configs/<config>.json``: the configuration's cut, merged into
   each of its top-level groups (every configuration has one);
 - ``tiny/traffic/<mix>.json``: a traffic mix's cut, where it needs one;
-- ``faults/<cell>.json``: where the cell's faults are planted
-  (``test_correct.py``);
+- ``faults/<cell>.json``: where the cell's faults are planted, and its
+  ``alter`` key naming ``alter/<name>.py``: what each fault does to the
+  output there (``test_correct.py``);
 - ``parity/<family>.json``: the program's synth that the family's
   generator copies (``test_gen.py``);
 - ``work/<metric>.json``: a roofline reader's work cases
@@ -35,7 +36,15 @@ HOOKS = Path(__file__).resolve().parent
 sys.path[:0] = [str(BENCH), str(ROOT)]
 
 BENCHMARK = json.loads((ROOT / "BENCHMARK.json").read_text())
-CELLS = [w["name"] for w in BENCHMARK["workloads"]]
+
+
+def cells(root: Path = ROOT) -> list[str]:
+    """The cells of the checkout at ``root``."""
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    return [w["name"] for w in bench["workloads"]]
+
+
+CELLS = cells()
 
 
 def _cut(path: Path, cut: dict) -> None:
@@ -46,12 +55,12 @@ def _cut(path: Path, cut: dict) -> None:
 
 
 def make_tiny(dest: Path) -> Path:
-    """A checkout at ``dest``: ``BENCHMARK.json`` and ``benchmark/`` copied
-    with each configuration and traffic mix cut to its ``tiny`` file; the
-    program linked in."""
+    """A checkout at ``dest``: ``BENCHMARK.json`` and ``benchmark/`` (its
+    tests and their hooks too) copied with each configuration and traffic
+    mix cut to its ``tiny`` file; the program linked in."""
     shutil.copy(ROOT / "BENCHMARK.json", dest / "BENCHMARK.json")
     shutil.copytree(BENCH, dest / "benchmark",
-                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+                    ignore=shutil.ignore_patterns("__pycache__"))
     for name in ("jepsen_tpu", "native"):
         (dest / name).symlink_to(ROOT / name)
     for conf in BENCHMARK["configs"]:

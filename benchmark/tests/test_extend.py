@@ -1,6 +1,7 @@
 """A later PR adds a configuration, a traffic mix, a cell, a per-layer
-metric and a checker family as files and entries alone: the harness finds
-them by name, and no file that was there changes."""
+metric, a checker family and the alteration its cell's faults make as
+files and entries alone: the harness and its tests find them by name, and
+no file that was there changes."""
 
 import hashlib
 import json
@@ -9,6 +10,7 @@ import subprocess
 import sys
 
 from conftest import run_tiny
+from test_correct import check_hooks, fault_cases, plant
 
 READER = '''"""Campaign passes the window completed."""
 
@@ -216,6 +218,59 @@ def test_new_family_from_files(tiny):
     assert set(sound["metrics"]) == {"histories_per_s", "setup_s"}
     assert off["correct"] is False
     assert off["checks"]["mismatched"]["value"] == 8
+
+    after = _digests(tiny)
+    assert {p: d for p, d in after.items() if p in before} == before
+
+
+FLIP = '''"""Turns over the first history's first sub-verdict alone."""
+
+import dataclasses
+
+
+def answer(out):
+    first, *rest = out if isinstance(out, tuple) else (out,)
+    first = dataclasses.replace(first, valid=first.valid.at[0].set(
+        ~first.valid[0]))
+    return (first, *rest) if isinstance(out, tuple) else first
+'''
+
+
+def test_new_alteration_from_files(tiny, monkeypatch):
+    """A copy of the first replay cell, under a mix of its own, whose
+    faults file names an alteration file that is new: the fault listing
+    finds its cases, its sound run is correct, its planted answer fault
+    reads not correct, and no file that was there changes."""
+    before = _digests(tiny)
+    bench = tiny / "benchmark"
+    path = tiny / "BENCHMARK.json"
+    b = json.loads(path.read_text())
+    base = next(w for w in b["workloads"] if json.loads(
+        (bench / "traffic" / f"{w['traffic']}.json").read_text()
+    )["driver"] == "replay")
+    cell = f"{base['config']}.flip"
+    (bench / "traffic" / "replay-flip.json").write_bytes(
+        (bench / "traffic" / f"{base['traffic']}.json").read_bytes())
+    hooks = bench / "tests"
+    site = json.loads((hooks / "faults" / f"{base['name']}.json")
+                      .read_text())["verdict"]
+    (hooks / "faults" / f"{cell}.json").write_text(json.dumps(
+        {"verdict": site, "alter": "flip"}))
+    (hooks / "alter" / "flip.py").write_text(FLIP)
+    b["workloads"].append({**base, "name": cell, "traffic": "replay-flip"})
+    for m in b["end_to_end"] + b["per_layer"]:
+        if base["name"] in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    path.write_text(json.dumps(b))
+
+    check_hooks(cell, tiny)
+    assert [f for f, c in fault_cases(tiny) if c == cell] == [
+        "answer_altered", "state_unchanged"]
+    assert run_tiny(tiny, cell)["correct"] is True
+    plant(monkeypatch, "answer_altered", cell, tiny)
+    line = run_tiny(tiny, cell)
+    assert line["correct"] is False
+    assert line["checks"]["mismatched"]["value"] > 0
 
     after = _digests(tiny)
     assert {p: d for p, d in after.items() if p in before} == before

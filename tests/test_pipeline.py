@@ -110,6 +110,125 @@ class TestStreamDifferential:
             )
 
 
+MB = 1_000_000
+
+
+@pytest.mark.parametrize(
+    "n, sizes, chunk, hist, want",
+    [
+        # the stream campaign: 64 x ~2.9 MB asks for 8 chunks by bytes
+        pytest.param(64, [2_930_000] * 8, 64, 1, (8, "bytes"), id="stream"),
+        # the queue campaign: 1000 x ~86 KB keeps 64-history chunks
+        pytest.param(1000, [86_000] * 8, 64, 1, (64, "count"), id="queue"),
+        # a caller's smaller chunk already cuts finer than the bytes ask
+        pytest.param(64, [2_930_000] * 8, 4, 1, (4, "count"),
+                     id="small-chunk"),
+        # ... and a chunk between the two still caps what the bytes give
+        pytest.param(64, [2_930_000] * 8, 32, 1, (8, "bytes"),
+                     id="mid-chunk"),
+        # 64 histories / 8 chunks = 8, rounded up to the hist extent 3
+        pytest.param(64, [2_930_000] * 8, 64, 3, (9, "bytes"), id="mesh"),
+        # rounding to the extent that reaches the cap keeps the count rule
+        pytest.param(64, [2_930_000] * 8, 16, 16, (16, "count"),
+                     id="mesh-cap"),
+        # the sample's mean stands for every source
+        pytest.param(64, [2_000_000, 3_860_000], 64, 1, (8, "bytes"),
+                     id="sample-mean"),
+        pytest.param(1, [2_930_000], 64, 1, (64, "count"), id="one-source"),
+        pytest.param(1, [200 * MB], 64, 1, (1, "bytes"),
+                     id="one-huge-source"),
+        # substrates, not paths: the count rule
+        pytest.param(64, None, 64, 1, (64, "count"), id="not-paths"),
+    ],
+)
+def test_plan_chunk(n, sizes, chunk, hist, want):
+    from jepsen_tpu.parallel.pipeline import _chunks, _pad_for, _plan_chunk
+
+    assert _plan_chunk(n, sizes, chunk, hist) == want
+    if n == 1000:
+        # today's chunks: the 40-history tail is padded to the 64
+        tail = _chunks(list(range(n)), want[0])[-1]
+        assert len(tail) == 40 and _pad_for(want[0], {}) == 64
+
+
+class TestChunkByBytes:
+    """A call whose files hold more than ``CHUNK_BYTES`` a chunk is cut
+    into several chunks: the verdicts are those of one chunk, in order,
+    and those of the serial path."""
+
+    @staticmethod
+    def _split(monkeypatch, files, parts):
+        """Set ``CHUNK_BYTES`` so that the call asks for ``parts``
+        chunks by its sampled bytes."""
+        from jepsen_tpu.parallel import pipeline
+
+        sample = pipeline._sample_sizes(files)
+        monkeypatch.setattr(pipeline, "CHUNK_BYTES", -(
+            -sum(sample) * len(files) // (len(sample) * parts)))
+
+    @staticmethod
+    def _by_bytes():
+        from jepsen_tpu.obs import metrics as obs_metrics
+
+        return obs_metrics.REGISTRY.value("pipeline.chunk_plans", by="bytes")
+
+    def test_sample_sizes(self, stream_corpus, tmp_path):
+        from jepsen_tpu.parallel.pipeline import SIZE_SAMPLE, _sample_sizes
+
+        _base, files = stream_corpus
+        assert _sample_sizes(files) == [
+            p.stat().st_size for p in files[:SIZE_SAMPLE]
+        ]
+        # a file that cannot be stat-ed weighs nothing; the producer
+        # reports it
+        assert _sample_sizes([tmp_path / "gone.jsonl", files[0]]) == [
+            0, files[0].stat().st_size
+        ]
+        assert _sample_sizes([np.zeros((1, 6), np.int32)]) is None
+
+    def test_split_equals_one_chunk_and_serial(
+        self, stream_corpus, monkeypatch
+    ):
+        _base, files = stream_corpus
+        one, one_stats = check_sources("stream", files)
+        assert one_stats.batches == 1
+        self._split(monkeypatch, files, 3)
+        before = self._by_bytes()
+        split, stats = check_sources("stream", files)
+        assert self._by_bytes() == before + 1
+        assert stats.batches == 3  # 14 histories: 5, 5, 4
+        serial, _ = check_sources("stream", files, serial=True)
+        assert split == one == serial
+
+    def test_split_reduced_on_mesh(self, tmp_path, cpu_devices,
+                                   monkeypatch):
+        """Reduce mode: the chunks' gids are offsets by the planned
+        length, so ``first_invalid`` names the same source."""
+        from jepsen_tpu.parallel.mesh import checker_mesh
+
+        # valid histories first: the first invalid one lies past the
+        # first chunk
+        files = _write(tmp_path, synth_stream_batch(
+            7, StreamSynthSpec(n_ops=40)) + synth_stream_batch(
+            7, StreamSynthSpec(n_ops=40, seed=100), lost=1))
+        per = [r["stream"]["valid?"]
+               for r in check_sources("stream", files)[0]]
+        bad = [i for i, v in enumerate(per) if v is False]
+        assert bad and bad[0] >= 6
+        want = {"histories": len(files), "invalid": len(bad),
+                "first_invalid": bad[0], "quarantined": 0, "dropped": 0}
+        mesh = checker_mesh(cpu_devices[:2], hist=2)
+        one, _ = check_sources("stream", files, mesh=mesh, reduce=True)
+        self._split(monkeypatch, files, 3)
+        before = self._by_bytes()
+        # 14 / 3 -> 5, rounded up to the hist extent: chunks of 6, 6, 2
+        split, stats = check_sources("stream", files, mesh=mesh,
+                                     reduce=True)
+        assert self._by_bytes() == before + 1
+        assert stats.batches == 3
+        assert split == one == want
+
+
 class TestQueueDifferential:
     def test_pipeline_equals_serial_equals_cpu(self, queue_corpus):
         base, files = queue_corpus
